@@ -34,6 +34,7 @@ legacy ridge-regularized matching formulation, bit-for-bit.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import NamedTuple, Optional, Sequence
@@ -42,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.projections import ProjectionMap, UnitSimplexProjection
 from repro.instances.buckets import (
     Bucket,
@@ -57,7 +59,14 @@ __all__ = [
     "gather_atl",
     "normalize_rows",
     "normalize_rows_traced",
+    "oracle_scope",
 ]
+
+# The oracle's scope names (`oracle_scope`) are the compiled program's own
+# telemetry.  JAX's persistent compilation cache keys a program without its
+# op metadata unless told otherwise, and would hand back an executable
+# compiled under another version's names.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 class DualEval(NamedTuple):
@@ -74,6 +83,15 @@ def _acc32(x: jax.Array) -> jax.Array:
     """Widen narrow primal slabs to fp32 before self-reductions (host-level
     dtype branch: identity object, identical jaxpr, for fp32 inputs)."""
     return x if x.dtype == jnp.float32 else x.astype(jnp.float32)
+
+
+@contextlib.contextmanager
+def oracle_scope(sublayer: str, length: int):
+    """Name the ops of one oracle sub-layer on one bucket: their op names in
+    the compiled program (and `tf_op` in a profiler trace) read
+    `.../oracle.<sublayer>/L<length>/...` (docs/observability.md)."""
+    with jax.named_scope(f"oracle.{sublayer}"), jax.named_scope(f"L{length}"):
+        yield
 
 
 def gather_atl(idx: jax.Array, coeff: jax.Array, lam2: jax.Array) -> jax.Array:
@@ -99,7 +117,8 @@ def _gather_at_lam(bucket: Bucket, lam2: jax.Array) -> jax.Array:
     and [132923, 4]; [1521024, 8] takes two seconds); slot-major, a second
     or two for every shape.
     """
-    return gather_atl(bucket.idx.T, bucket.coeff.swapaxes(1, 2), lam2).T
+    with oracle_scope("gather", bucket.idx.shape[1]):
+        return gather_atl(bucket.idx.T, bucket.coeff.swapaxes(1, 2), lam2).T
 
 
 def binned_segment_sum(idx: jax.Array, contrib: jax.Array, J: int) -> jax.Array:
@@ -122,8 +141,9 @@ def binned_segment_sum(idx: jax.Array, contrib: jax.Array, J: int) -> jax.Array:
 
 def _segment_sum_ax(bucket: Bucket, x: jax.Array, J: int) -> jax.Array:
     """This bucket's contribution to A x: [m, J]."""
-    contrib = bucket.coeff * (x * bucket.mask)[None]  # [m, n, L]
-    return binned_segment_sum(bucket.idx, contrib, J)
+    with oracle_scope("scatter", bucket.idx.shape[1]):
+        contrib = bucket.coeff * (x * bucket.mask)[None]  # [m, n, L]
+        return binned_segment_sum(bucket.idx, contrib, J)
 
 
 @dataclasses.dataclass
@@ -250,8 +270,10 @@ class MatchingObjective:
         gamma_eff = self._scaled_gamma(gamma)
         slabs = []
         for i, b in enumerate(self._buckets):
-            z = -(_gather_at_lam(b, lam2) + self._scaled_cost(b)) / gamma_eff
-            slabs.append(self._proj(i)(z, b.mask))
+            atl = _gather_at_lam(b, lam2)
+            with oracle_scope("project", b.idx.shape[1]):
+                z = -(atl + self._scaled_cost(b)) / gamma_eff
+                slabs.append(self._proj(i)(z, b.mask))
         return tuple(slabs)
 
     def apply_A(self, x_slabs: Sequence[jax.Array]) -> jax.Array:
@@ -377,7 +399,8 @@ class MatchingObjective:
             au = self.apply_A(atl)
             return au, jnp.linalg.norm(au)
 
-        _, norms = jax.lax.scan(body, u0, None, length=iters)
+        with jax.named_scope("power_iteration"):
+            _, norms = jax.lax.scan(body, u0, None, length=iters)
         return norms[-1]  # ~ sigma_max^2
 
 
@@ -450,42 +473,43 @@ def normalize_rows(
     feasible set is unchanged; duals map back as lam_original = D lam'.
     Host-side transform: runs once at instance build time, before sharding.
     """
-    m, J = inst.num_families, inst.num_destinations
-    norms = np.sqrt(inst.row_norms_sq())
-    d = np.where(norms > eps, 1.0 / np.maximum(norms, eps), 1.0)
-    d2 = d.reshape(m, J)
-    buckets = []
-    for b in inst.buckets:
-        idx = np.asarray(b.idx)
-        scale = d2[:, idx]  # [m, n, L]
-        if b.coeff_scale is not None:
-            # quantized (int8) slabs: dequantize, apply the Jacobi scaling in
-            # fp32, requantize with fresh symmetric per-family scales
-            coeff_f32 = np.asarray(b.coeff, np.float32) * np.asarray(
-                b.coeff_scale, np.float32
-            )
-            q, new_scale = _quantize_sym(
-                (coeff_f32 * scale).astype(np.float32), axes=(1, 2)
-            )
+    with telemetry.span("normalize_rows"):
+        m, J = inst.num_families, inst.num_destinations
+        norms = np.sqrt(inst.row_norms_sq())
+        d = np.where(norms > eps, 1.0 / np.maximum(norms, eps), 1.0)
+        d2 = d.reshape(m, J)
+        buckets = []
+        for b in inst.buckets:
+            idx = np.asarray(b.idx)
+            scale = d2[:, idx]  # [m, n, L]
+            if b.coeff_scale is not None:
+                # quantized (int8) slabs: dequantize, apply the Jacobi scaling in
+                # fp32, requantize with fresh symmetric per-family scales
+                coeff_f32 = np.asarray(b.coeff, np.float32) * np.asarray(
+                    b.coeff_scale, np.float32
+                )
+                q, new_scale = _quantize_sym(
+                    (coeff_f32 * scale).astype(np.float32), axes=(1, 2)
+                )
+                buckets.append(
+                    dataclasses.replace(
+                        b, idx=idx, coeff=q, coeff_scale=new_scale,
+                        cost=np.asarray(b.cost), mask=np.asarray(b.mask),
+                    )
+                )
+                continue
             buckets.append(
-                dataclasses.replace(
-                    b, idx=idx, coeff=q, coeff_scale=new_scale,
-                    cost=np.asarray(b.cost), mask=np.asarray(b.mask),
+                Bucket(
+                    idx=idx,
+                    coeff=(np.asarray(b.coeff) * scale).astype(b.coeff.dtype),
+                    cost=np.asarray(b.cost),
+                    mask=np.asarray(b.mask),
+                    length=b.length,
                 )
             )
-            continue
-        buckets.append(
-            Bucket(
-                idx=idx,
-                coeff=(np.asarray(b.coeff) * scale).astype(b.coeff.dtype),
-                cost=np.asarray(b.cost),
-                mask=np.asarray(b.mask),
-                length=b.length,
-            )
+        scaled = dataclasses.replace(
+            inst,
+            buckets=tuple(buckets),
+            rhs=(np.asarray(inst.rhs) * d).astype(inst.rhs.dtype),
         )
-    scaled = dataclasses.replace(
-        inst,
-        buckets=tuple(buckets),
-        rhs=(np.asarray(inst.rhs) * d).astype(inst.rhs.dtype),
-    )
-    return scaled, d
+        return scaled, d

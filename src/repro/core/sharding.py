@@ -306,7 +306,8 @@ class DistributedMaximizer:
                 au = jax.lax.psum(obj.apply_A(atl), axes)
                 return au, jnp.linalg.norm(au)
 
-            _, norms = jax.lax.scan(body, u0, None, length=cfg.power_iters)
+            with jax.named_scope("power_iteration"):
+                _, norms = jax.lax.scan(body, u0, None, length=cfg.power_iters)
             return norms[-1]
 
         self._power_fn = jax.jit(power_fn)

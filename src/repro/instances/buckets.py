@@ -38,6 +38,7 @@ import jax
 import ml_dtypes
 import numpy as np
 
+from repro import telemetry
 from repro.instances.generator import EdgeListInstance
 
 __all__ = [
@@ -287,77 +288,78 @@ def bucketize(
     packed in fp32 and converted per bucket, and the rhs stays fp32 for
     narrow dtypes (dual space is always fp32).
     """
-    slab_dt = resolve_slab_dtype(dtype)
-    spec = inst.spec
-    I, J, m = spec.num_sources, spec.num_destinations, spec.num_families
+    with telemetry.span("bucketize"):
+        slab_dt = resolve_slab_dtype(dtype)
+        spec = inst.spec
+        I, J, m = spec.num_sources, spec.num_destinations, spec.num_families
 
-    deg = np.bincount(inst.src, minlength=I)
-    active = np.flatnonzero(deg)  # sources with at least one edge
-    if active.size == 0:
-        raise ValueError("instance has no edges")
-    # edge offsets per source (sorted by src)
-    starts = np.zeros(I + 1, dtype=np.int64)
-    np.cumsum(deg, out=starts[1:])
+        deg = np.bincount(inst.src, minlength=I)
+        active = np.flatnonzero(deg)  # sources with at least one edge
+        if active.size == 0:
+            raise ValueError("instance has no edges")
+        # edge offsets per source (sorted by src)
+        starts = np.zeros(I + 1, dtype=np.int64)
+        np.cumsum(deg, out=starts[1:])
 
-    max_deg = int(deg.max())
-    cap = _next_pow2(max_deg)
-    if max_length is not None:
-        if cap > max_length:
-            raise ValueError(
-                f"max degree {max_deg} exceeds max bucket length {max_length}"
+        max_deg = int(deg.max())
+        cap = _next_pow2(max_deg)
+        if max_length is not None:
+            if cap > max_length:
+                raise ValueError(
+                    f"max degree {max_deg} exceeds max bucket length {max_length}"
+                )
+        lengths = []
+        L = max(1, _next_pow2(min_length))
+        cap = max(cap, L)
+        while L <= cap:
+            lengths.append(L)
+            L *= 2
+        # bucket index per active source: smallest L >= degree, but >= min length
+        b_of = np.searchsorted(np.asarray(lengths), deg[active])
+
+        buckets: list[Bucket] = []
+        info = _PackInfo(source_ids=[], edge_starts=[], degrees=[])
+        for t, Lt in enumerate(lengths):
+            rows_src = active[b_of == t]
+            n = _pad_rows(rows_src.size, shard_multiple)
+            idx = np.zeros((n, Lt), dtype=np.int32)
+            coeff = np.zeros((m, n, Lt), dtype=np.float32)
+            cost = np.zeros((n, Lt), dtype=np.float32)
+            mask = np.zeros((n, Lt), dtype=np.float32)
+            d = deg[rows_src]
+            st = starts[rows_src]
+            # vectorised slab fill: flat positions of each (row, within-slice) pair
+            if rows_src.size:
+                r = np.repeat(np.arange(rows_src.size), d)
+                o = np.concatenate([np.arange(k) for k in d]) if d.size else np.empty(0, int)
+                e = np.repeat(st, d) + o
+                idx[r, o] = inst.dst[e]
+                cost[r, o] = inst.cost[e]
+                mask[r, o] = 1.0
+                for k in range(m):
+                    coeff[k, r, o] = inst.coeff[k, e]
+            buckets.append(
+                convert_bucket(
+                    Bucket(idx=idx, coeff=coeff, cost=cost, mask=mask, length=Lt),
+                    slab_dt,
+                )
             )
-    lengths = []
-    L = max(1, _next_pow2(min_length))
-    cap = max(cap, L)
-    while L <= cap:
-        lengths.append(L)
-        L *= 2
-    # bucket index per active source: smallest L >= degree, but >= min length
-    b_of = np.searchsorted(np.asarray(lengths), deg[active])
+            sid = np.full(n, -1, dtype=np.int64)
+            sid[: rows_src.size] = rows_src
+            info.source_ids.append(sid)
+            info.edge_starts.append(st)
+            info.degrees.append(d)
 
-    buckets: list[Bucket] = []
-    info = _PackInfo(source_ids=[], edge_starts=[], degrees=[])
-    for t, Lt in enumerate(lengths):
-        rows_src = active[b_of == t]
-        n = _pad_rows(rows_src.size, shard_multiple)
-        idx = np.zeros((n, Lt), dtype=np.int32)
-        coeff = np.zeros((m, n, Lt), dtype=np.float32)
-        cost = np.zeros((n, Lt), dtype=np.float32)
-        mask = np.zeros((n, Lt), dtype=np.float32)
-        d = deg[rows_src]
-        st = starts[rows_src]
-        # vectorised slab fill: flat positions of each (row, within-slice) pair
-        if rows_src.size:
-            r = np.repeat(np.arange(rows_src.size), d)
-            o = np.concatenate([np.arange(k) for k in d]) if d.size else np.empty(0, int)
-            e = np.repeat(st, d) + o
-            idx[r, o] = inst.dst[e]
-            cost[r, o] = inst.cost[e]
-            mask[r, o] = 1.0
-            for k in range(m):
-                coeff[k, r, o] = inst.coeff[k, e]
-        buckets.append(
-            convert_bucket(
-                Bucket(idx=idx, coeff=coeff, cost=cost, mask=mask, length=Lt),
-                slab_dt,
-            )
+        out = BucketedInstance(
+            buckets=tuple(buckets),
+            rhs=inst.rhs.astype(rhs_dtype(slab_dt)),
+            num_sources=I,
+            num_destinations=J,
+            num_families=m,
         )
-        sid = np.full(n, -1, dtype=np.int64)
-        sid[: rows_src.size] = rows_src
-        info.source_ids.append(sid)
-        info.edge_starts.append(st)
-        info.degrees.append(d)
-
-    out = BucketedInstance(
-        buckets=tuple(buckets),
-        rhs=inst.rhs.astype(rhs_dtype(slab_dt)),
-        num_sources=I,
-        num_destinations=J,
-        num_families=m,
-    )
-    _PACK_INFO[id(out)] = info
-    weakref.finalize(out, _PACK_INFO.pop, id(out), None)
-    return out
+        _PACK_INFO[id(out)] = info
+        weakref.finalize(out, _PACK_INFO.pop, id(out), None)
+        return out
 
 
 def pack_single_slab(

@@ -243,4 +243,12 @@ def make_dual_oracle_call(
         ),
         scratch_shapes=[pltpu.VMEM((m, length, block), jnp.float32)],
         interpret=interpret,
+        name="dual_oracle",
+        metadata={
+            "length": str(length),
+            "families": str(m),
+            "destinations": str(num_destinations),
+            "block": str(block),
+            "dtype": jnp.dtype(dtype).name,
+        },
     )

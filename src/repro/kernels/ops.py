@@ -30,7 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import gather_atl
+from repro.core.objective import gather_atl, oracle_scope
 from repro.kernels import ref as kref
 from repro.kernels.dual_oracle import (
     _padded_destinations,
@@ -237,9 +237,10 @@ def fused_dual_primal(
     c = cost.T.astype(jnp.float32)
     if quantized:
         c = c * jnp.asarray(cost_scale, jnp.float32).reshape(1, 1)
-    atl = _gather_atl(
-        idx.T, coeff.swapaxes(1, 2), lam, num_destinations, coeff_scale
-    )
+    with oracle_scope("gather", L):
+        atl = _gather_atl(
+            idx.T, coeff.swapaxes(1, 2), lam, num_destinations, coeff_scale
+        )
     v = -(atl + c) / jnp.asarray(gamma, jnp.float32)
     return _simplex_kernel(
         v, mask.T, radius=radius, inequality=inequality,
@@ -324,8 +325,10 @@ def fused_dual_oracle(
         out_dtype=_primal_out_dtype(cost.dtype, quantized),
     )
     idx_t, coeff_t = idx.T, coeff.swapaxes(1, 2)
+    with oracle_scope("gather", L):
+        atl = _gather_atl(idx_t, coeff_t, lam, num_destinations, coeff_scale)
     operands = [
-        _gather_atl(idx_t, coeff_t, lam, num_destinations, coeff_scale),
+        atl,
         idx_t,
         coeff_t,
         cost.T,
@@ -337,13 +340,14 @@ def fused_dual_oracle(
             jnp.asarray(coeff_scale, jnp.float32).reshape(m, 1),
             jnp.asarray(cost_scale, jnp.float32).reshape(1, 1),
         ]
-    x, hist_p, scal_p = call(*operands)
-    return (
-        x.T,
-        hist_p.sum(axis=0)[:, :num_destinations],
-        scal_p[:, 0, 0].sum(),
-        scal_p[:, 0, 1].sum(),
-    )
+    with oracle_scope("kernel", L):
+        x, hist_p, scal_p = call(*operands)
+        return (
+            x.T,
+            hist_p.sum(axis=0)[:, :num_destinations],
+            scal_p[:, 0, 0].sum(),
+            scal_p[:, 0, 1].sum(),
+        )
 
 
 def oracle_takes_kernel(

@@ -182,4 +182,10 @@ def make_simplex_call(
         in_specs=[spec, spec],
         out_specs=spec,
         interpret=interpret,
+        name="simplex_proj",
+        metadata={
+            "length": str(length),
+            "block": str(block),
+            "dtype": jnp.dtype(dtype).name,
+        },
     )

@@ -36,13 +36,12 @@ __all__ = ["Span", "Tracer", "get_tracer", "set_tracer", "span"]
 class Span:
     """One open span; exposed so callers can attach late attributes."""
 
-    __slots__ = ("name", "args", "t0", "wall0", "depth", "parent")
+    __slots__ = ("name", "args", "t0", "depth", "parent")
 
     def __init__(self, name: str, args: dict, depth: int, parent: Optional["Span"]):
         self.name = name
         self.args = args
         self.t0 = time.perf_counter()
-        self.wall0 = time.time()
         self.depth = depth
         self.parent = parent
 

@@ -10,6 +10,7 @@ more VMEM than the chip allows.  Nothing runs; results are checked by the
 interpret-mode parity tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +117,35 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, L, m, dtype):
         )
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["dual_oracle", "simplex_proj"])
+def test_kernel_name_and_metadata_in_compiled_text(one_chip, kernel):
+    """The kernels carry their names and metadata into the compiled op's
+    HLO text, which is the op's name in a profiler trace, and the dual
+    oracle's ops sit under the program's `oracle.*` scopes
+    (docs/observability.md)."""
+    L = 16
+    if kernel == "simplex_proj":
+        v = jax.ShapeDtypeStruct((ROWS, L), jnp.float32, sharding=one_chip)
+        lowered = jax.jit(
+            lambda v, mask: kops.fused_project_simplex(v, mask, interpret=False)
+        ).lower(v, v)
+    else:
+        lowered = jax.jit(_primal_step(kops.fused_dual_oracle)).lower(
+            *_slab_args(one_chip, L, 1, jnp.float32)
+        )
+    text = lowered.compile().as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert call.lstrip().startswith(f"%{kernel}")
+    meta = re.search(r"kernel_metadata=\{(.*?)\}", text, re.S)
+    assert meta is not None
+    assert f'"length":"{L}"' in meta.group(1)
+    assert '"dtype":"float32"' in meta.group(1)
+    if kernel == "dual_oracle":
+        assert f'"destinations":"{J}"' in meta.group(1)
+        assert f"oracle.kernel/L{L}/dual_oracle" in text
+        gathers = re.findall(r" gather\(.*?op_name=\"([^\"]*)\"", text)
+        assert gathers
+        assert all(f"oracle.gather/L{L}/" in n for n in gathers)
