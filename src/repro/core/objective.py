@@ -116,9 +116,16 @@ def _gather_at_lam(bucket: Bucket, lam2: jax.Array) -> jax.Array:
     scatter-add takes the TPU compiler about two minutes (e.g. [1521027, 8]
     and [132923, 4]; [1521024, 8] takes two seconds); slot-major, a second
     or two for every shape.
+
+    On TPU the one-hot gather kernel does the work
+    (`kernels.ops.dual_gather`, bit for bit `gather_atl`'s at m = 1).
     """
+    from repro.kernels import ops as kops
+
     with oracle_scope("gather", bucket.idx.shape[1]):
-        return gather_atl(bucket.idx.T, bucket.coeff.swapaxes(1, 2), lam2).T
+        return kops.dual_gather(
+            bucket.idx.T, bucket.coeff.swapaxes(1, 2), lam2
+        ).T
 
 
 def binned_segment_sum(idx: jax.Array, contrib: jax.Array, J: int) -> jax.Array:

@@ -8,9 +8,13 @@ dual_oracle.py   one-pass fusion of the rest of the oracle: projection of
                  histograms (one-hot accumulation on the VPU against the
                  [m, J] dual shape) and (c'x, ||x||^2) partials, so one
                  launch per bucket yields g, grad and x.
-ops.py           jit'd wrappers: the XLA gather of A^T lam (Mosaic cannot
-                 gather from a J-wide row), slab transposes, block sizing,
-                 bucket dispatch, >8192-width fallback, interpret/TPU switch.
+gather.py        the gather of A^T lam as an exact one-hot contraction on
+                 the MXU against a VMEM-resident byte table of the duals
+                 (Mosaic gathers only within one vector register).
+ops.py           jit'd wrappers: the gather's dispatch (kernel on TPU for
+                 J <= 16384 per family, else XLA's gather), slab
+                 transposes, block sizing, bucket dispatch, >8192-width
+                 fallback, interpret/TPU switch.
 ref.py           pure-jnp oracles (the kernel tests' ground truth and the
                  off-TPU execution path of the fused dual oracle).
 

@@ -15,10 +15,11 @@ kernels/simplex_proj.py for why), and the grid runs over blocks of sources:
     hist[i]  = this tile's binned contribution to A x  -> [1, m, Jp]
     scal[i]  = (sum c*x_tile, sum x_tile^2)            -> [1, 8, 128]
 
-`atl = A^T lam` restricted to the slab is computed by XLA before the call
-(`ops._gather_atl`): a gather from a J-wide dual row by an index tile has no
-Mosaic lowering (Mosaic gathers only within one vector register), so the
-kernel reads the gathered `atl` slab instead of gathering itself.
+`atl = A^T lam` restricted to the slab is gathered before the call
+(`ops.dual_gather`), on TPU by its own kernel (kernels/gather.py): Mosaic
+gathers only within one vector register, so that kernel writes the gather
+as an exact one-hot contraction on the MXU, and this kernel reads the
+gathered `atl` slab.
 
 The in-kernel binned scatter is a **one-hot accumulation**, not a scatter:
 TPU has no VMEM scatter-add, but `hist[k, j] += coeff[k,e] * x[e]` over the

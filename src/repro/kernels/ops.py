@@ -9,8 +9,10 @@ Responsibilities (mirrors the paper's dispatch policy, §4.3):
     footprint: slot depth rounded up to the sublane tiling of the slab dtype
     (8 for fp32, 16 for bf16, 32 for int8), sources a multiple of 128 lanes;
     the last grid step may be partial and the kernels mask it;
-  * gather `A^T lam` in XLA ahead of the kernels (`_gather_atl`): a gather
-    from a J-wide dual row has no Mosaic lowering;
+  * gather `A^T lam` ahead of the kernels (`dual_gather`): on TPU for
+    J <= 16384 per family, as many families as its VMEM holds, through the
+    one-hot MXU kernel (kernels/gather.py, `gather_takes_kernel`), else
+    through XLA's gather (`gather_atl`);
   * fall back to the multi-op reference implementation for non-power-of-two
     slab widths and for widths beyond KERNEL_MAX_LENGTH = 512, where a
     single 128-source tile fills the VMEM budget (the paper's Triton kernel
@@ -37,14 +39,23 @@ from repro.kernels.dual_oracle import (
     fits_onehot_budget,
     make_dual_oracle_call,
 )
+from repro.kernels.gather import (
+    dual_table,
+    fits_vmem_budget,
+    make_gather_call,
+    tile_rows,
+)
 from repro.kernels.simplex_proj import MAX_FUSED_LENGTH, make_simplex_call
 
 __all__ = [
+    "dual_gather",
     "fused_project_simplex",
     "fused_dual_primal",
     "fused_dual_oracle",
     "fused_pdhg_step",
     "KERNEL_MAX_LENGTH",
+    "gather_takes_kernel",
+    "onehot_gather",
     "oracle_hist_partial_bytes",
     "oracle_takes_kernel",
     "oracle_slab_slot_bytes",
@@ -97,8 +108,9 @@ def _primal_out_dtype(storage_dtype, quantized: bool):
 def oracle_slab_slot_bytes(num_families: int, slab_dtype="float32") -> int:
     """Analytic per-slot HBM bytes of one fused-oracle iteration.
 
-    The XLA gather of `A^T lam` reads idx (int32) and the m coeff families
-    at the storage width and writes the fp32 atl slab; the kernel reads
+    The gather of `A^T lam` (`dual_gather`) reads idx (int32) and the m
+    coeff families at the storage width and writes the fp32 atl slab (its
+    O(m·J) dual tables are not per slot); the kernel reads
     atl, idx, the coeff families, cost and mask, and writes x at the
     primal-out width (storage width for float slabs, fp32 for int8).
     Shared by `launch.dryrun` and `benchmarks.table2_iteration_time` — the
@@ -147,17 +159,88 @@ def _use_interpret(interpret) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _gather_atl(idx, coeff, lam, num_destinations, coeff_scale=None):
-    """A^T lam on one bucket slab in fp32 (any slab orientation), with the
-    int8 coefficients dequantized."""
-    m = coeff.shape[0]
+def gather_takes_kernel(
+    num_destinations: int, num_families: int, dtype, quantized: bool,
+    interpret,
+) -> bool:
+    """Whether `dual_gather` runs the one-hot kernel (kernels/gather.py) or
+    XLA's gather, for m families of J duals and coefficients stored as
+    `dtype`.
+
+    The kernel takes the TPU backend (`interpret=None`), or interpret mode
+    when asked (`interpret=True`), with a family's table at most
+    MAX_TABLE_ROWS = 128 rows of 128 duals (J <= 16384) and its VMEM, which
+    grows with m·R, within budget (`gather.fits_vmem_budget`: m <= 4 at
+    J = 16384, m <= 5 at J = 10^4).  Cost model, per
+    slot and family, R = ceil(J/128): the kernel's MXU work is 2·4·128·R
+    flops and its vector work about 5R + 260 operations; XLA's gather costs
+    ~7.5 ns per slot on a v5e whatever J is (1.3·10^8 slots/s at J = 10^4).
+    At R = 80 (J = 10^4) the kernel, MXU-bound, took 9.4 ms for 2.05·10^7
+    slots on a v5e against XLA's 157 ms: 0.46 ns per slot, growing in
+    proportion to R, so the two would cross near R = 1300 (J ~ 1.7·10^5).
+    The kernel stops at R = 128, where its tables and contraction results
+    still leave room in VMEM for several families; wider duals, or more
+    families than fit, take XLA.  Off-TPU without `interpret=True`, XLA.
+    """
+    if interpret is None and jax.default_backend() != "tpu":
+        return False
+    return fits_vmem_budget(num_families, num_destinations) and (
+        _kernel_supports_dtype(dtype, quantized)
+    )
+
+
+def dual_gather(idx, coeff, lam2, *, coeff_scale=None, interpret=None):
+    """A^T lam on one transposed [L, n] slab, in fp32:
+    sum_k coeff[k] · lam2[k, idx], with int8 coefficients dequantized by
+    `coeff_scale` ([m] values in any shape).
+
+    idx [L, n] int32, coeff [m, L, n], lam2 [m, J].  Through the one-hot
+    kernel where `gather_takes_kernel` says so and the duals are fp32 (its
+    result is `gather_atl`'s bit for bit at m = 1), under a nested
+    `onehot` scope; else `gather_atl`.
+    """
+    m, J = lam2.shape
+    quantized = coeff_scale is not None
+    if lam2.dtype == jnp.float32 and gather_takes_kernel(
+        J, m, coeff.dtype, quantized, interpret
+    ):
+        return onehot_gather(
+            idx, coeff, lam2, coeff_scale, interpret=interpret
+        )
     c = coeff.astype(jnp.float32)
-    if coeff_scale is not None:
+    if quantized:
         c = c * jnp.asarray(coeff_scale, jnp.float32).reshape(
             (m,) + (1,) * (coeff.ndim - 1)
         )
-    lam2 = lam.astype(jnp.float32).reshape(m, num_destinations)
     return gather_atl(idx, c, lam2)
+
+
+def onehot_gather(idx, coeff, lam2, coeff_scale=None, *, interpret=None):
+    """A^T lam [L, n] fp32 on one transposed [L, n] slab through the one-hot
+    gather kernel, under a nested `onehot` scope: idx [L, n], coeff
+    [m, L, n] (with `coeff_scale` for int8), lam2 [m, J] fp32."""
+    L, n = idx.shape
+    m, J = lam2.shape
+    narrow = min(coeff.dtype, idx.dtype, key=lambda d: jnp.dtype(d).itemsize)
+    call = make_gather_call(
+        n, L, m, J, pick_block_rows(n, tile_rows(L), narrow), coeff.dtype,
+        interpret=_use_interpret(interpret), quantized=coeff_scale is not None,
+    )
+    operands = [*dual_table(lam2), idx, coeff]
+    if coeff_scale is not None:
+        operands.append(jnp.asarray(coeff_scale, jnp.float32).reshape(m, 1))
+    with jax.named_scope("onehot"):
+        return call(*operands)
+
+
+def _gather_atl(idx, coeff, lam, num_destinations, coeff_scale=None,
+                interpret=None):
+    """A^T lam on one transposed [L, n] bucket slab in fp32 (`dual_gather`
+    of the flat duals)."""
+    lam2 = lam.astype(jnp.float32).reshape(coeff.shape[0], num_destinations)
+    return dual_gather(
+        idx, coeff, lam2, coeff_scale=coeff_scale, interpret=interpret
+    )
 
 
 @functools.partial(
@@ -223,8 +306,9 @@ def fused_dual_primal(
 ) -> jax.Array:
     """Whole primal step  x = Pi( -(A^T lam + c)/gamma )  for one bucket.
 
-    XLA forms the candidate (gather, axpy, scale in one fusion) and the
-    simplex kernel projects it, writing x at the storage width.
+    `dual_gather` gathers `A^T lam`, XLA forms the candidate (axpy and
+    scale) and the simplex kernel projects it, writing x at the storage
+    width.
     """
     n, L = cost.shape
     quantized = coeff_scale is not None
@@ -239,7 +323,8 @@ def fused_dual_primal(
         c = c * jnp.asarray(cost_scale, jnp.float32).reshape(1, 1)
     with oracle_scope("gather", L):
         atl = _gather_atl(
-            idx.T, coeff.swapaxes(1, 2), lam, num_destinations, coeff_scale
+            idx.T, coeff.swapaxes(1, 2), lam, num_destinations, coeff_scale,
+            interpret,
         )
     v = -(atl + c) / jnp.asarray(gamma, jnp.float32)
     return _simplex_kernel(
@@ -266,7 +351,8 @@ def fused_dual_oracle(
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One-pass fused dual oracle for one bucket: `(x, hist, lin, sq)`.
 
-    XLA gathers `A^T lam` onto the slab; one kernel launch then computes the
+    `dual_gather` gathers `A^T lam` onto the slab (the one-hot gather kernel
+    on TPU, kernels/gather.py); one kernel launch then computes the
     projected primal slab `x` AND this bucket's gradient/objective partials
     (`hist = A x` contribution [m, J], `lin = c'x`, `sq = ||x||^2`) from one
     read of the slab; the per-grid-step partials are tree-summed here
@@ -326,7 +412,9 @@ def fused_dual_oracle(
     )
     idx_t, coeff_t = idx.T, coeff.swapaxes(1, 2)
     with oracle_scope("gather", L):
-        atl = _gather_atl(idx_t, coeff_t, lam, num_destinations, coeff_scale)
+        atl = _gather_atl(
+            idx_t, coeff_t, lam, num_destinations, coeff_scale, interpret
+        )
     operands = [
         atl,
         idx_t,

@@ -14,6 +14,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -65,6 +66,25 @@ def _slab_args(sharding, L, m, dtype):
     return args
 
 
+def _gather(idx, coeff, lam2, *scale):
+    return kops.dual_gather(idx, coeff, lam2,
+                            coeff_scale=scale[0] if scale else None,
+                            interpret=False)
+
+
+def _lower_gather(sharding, L, m, dtype, destinations=J):
+    """`ops.dual_gather` on one transposed [L, n] slab with m families of
+    J duals, as the oracle calls it."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    args = [sds((L, ROWS), jnp.int32), sds((m, L, ROWS), dtype),
+            sds((m, destinations), jnp.float32)]
+    if dtype == jnp.int8:
+        args.append(sds((m, 1, 1), jnp.float32))
+    return jax.jit(_gather).lower(*args)
+
+
 def _primal_step(fn):
     def call(idx, coeff, cost, mask, lam, gamma, *scales):
         return fn(
@@ -94,6 +114,12 @@ CASES = [
     ("simplex", 512, 1, jnp.float32),
     ("dual_primal", 512, 1, jnp.float32),
     ("dual_oracle", 512, 1, jnp.float32),
+    # the one-hot gather of A^T lam: every bucket width of the benchmark's
+    # instances in each storage dtype, and three families
+    *[("dual_gather", L, 1, dt) for dt in (jnp.float32, jnp.bfloat16, jnp.int8)
+      for L in (1, 2, 4, 8, 16, 32)],
+    *[("dual_gather", L, 3, dt) for dt in (jnp.float32, jnp.bfloat16, jnp.int8)
+      for L in (1, 16)],
 ]
 
 
@@ -108,6 +134,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, L, m, dtype):
         lowered = jax.jit(
             lambda v, mask: kops.fused_project_simplex(v, mask, interpret=False)
         ).lower(v, v)
+    elif kernel == "dual_gather":
+        lowered = _lower_gather(one_chip, L, m, dtype)
     else:
         fn = kops.fused_dual_primal if kernel == "dual_primal" else (
             kops.fused_dual_oracle
@@ -119,33 +147,96 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, L, m, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kernel", ["dual_oracle", "simplex_proj"])
+@pytest.mark.parametrize("kernel", ["dual_oracle", "simplex_proj", "dual_gather"])
 def test_kernel_name_and_metadata_in_compiled_text(one_chip, kernel):
     """The kernels carry their names and metadata into the compiled op's
     HLO text, which is the op's name in a profiler trace, and the dual
     oracle's ops sit under the program's `oracle.*` scopes
-    (docs/observability.md)."""
+    (docs/observability.md): on the chip the oracle's gather is the
+    `dual_gather` kernel, under `oracle.gather/L<w>/onehot`."""
     L = 16
     if kernel == "simplex_proj":
         v = jax.ShapeDtypeStruct((ROWS, L), jnp.float32, sharding=one_chip)
         lowered = jax.jit(
             lambda v, mask: kops.fused_project_simplex(v, mask, interpret=False)
         ).lower(v, v)
+    elif kernel == "dual_gather":
+        lowered = _lower_gather(one_chip, L, 1, jnp.float32)
     else:
         lowered = jax.jit(_primal_step(kops.fused_dual_oracle)).lower(
             *_slab_args(one_chip, L, 1, jnp.float32)
         )
     text = lowered.compile().as_text()
     call = next(line for line in text.splitlines()
-                if 'custom_call_target="tpu_custom_call"' in line)
-    assert call.lstrip().startswith(f"%{kernel}")
-    meta = re.search(r"kernel_metadata=\{(.*?)\}", text, re.S)
+                if 'custom_call_target="tpu_custom_call"' in line
+                and re.match(rf"\s*(ROOT )?%{kernel}\b", line))
+    meta = re.search(r"kernel_metadata=\{(.*?)\}", text[text.index(call):], re.S)
     assert meta is not None
     assert f'"length":"{L}"' in meta.group(1)
     assert '"dtype":"float32"' in meta.group(1)
-    if kernel == "dual_oracle":
+    if kernel != "simplex_proj":
         assert f'"destinations":"{J}"' in meta.group(1)
+    if kernel == "dual_oracle":
         assert f"oracle.kernel/L{L}/dual_oracle" in text
+        assert f"oracle.gather/L{L}/onehot/dual_gather" in text
+        # no gather left to XLA, and none outside the oracle's scope
         gathers = re.findall(r" gather\(.*?op_name=\"([^\"]*)\"", text)
-        assert gathers
         assert all(f"oracle.gather/L{L}/" in n for n in gathers)
+    if kernel == "dual_gather":
+        assert "onehot/dual_gather" in text
+        assert not re.findall(r" gather\(", text)
+
+
+@pytest.mark.parametrize("destinations,m,L", [
+    (16_384, 3, 16), (16_384, 4, 1), (16_384, 4, 16), (10_000, 5, 16),
+    (1000, 8, 16)])
+def test_gather_compiles_at_its_vmem_limit(one_chip, destinations, m, L):
+    """The most families `gather.fits_vmem_budget` lets the kernel take,
+    at the widest fp32 tiles: the kernel compiles."""
+    from repro.kernels.gather import fits_vmem_budget
+
+    assert fits_vmem_budget(m, destinations)
+    text = _lower_gather(one_chip, L, m, jnp.float32, destinations).compile(
+    ).as_text()
+    assert "tpu_custom_call" in text and not re.findall(r" gather\(", text)
+
+
+@pytest.mark.parametrize("shared", [False, True], ids=["batched", "shared"])
+@pytest.mark.parametrize("destinations", [1000, J])
+def test_gather_compiles_under_vmap(one_chip, shared, destinations):
+    """The service's batched pool vmaps the solve over tenants: the
+    gather's pallas_call under jax.vmap, with each tenant's slabs or with
+    one slab shared by the tenants, and each tenant's duals."""
+    T, L, m, n = 4, 8, 1, ROWS // 4
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lead = () if shared else (T,)
+    args = [sds(lead + (L, n), jnp.int32), sds(lead + (m, L, n), jnp.float32),
+            sds((T, m, destinations), jnp.float32)]
+    batched = jax.vmap(_gather, in_axes=(None if shared else 0,) * 2 + (0,))
+    text = jax.jit(batched).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and not re.findall(r" gather\(", text)
+
+
+def test_gather_compiles_inside_shard_map(one_chip, topo):
+    """The sharded driver gathers inside shard_map (core/sharding.py:
+    sources split over the mesh, duals replicated, check_vma off): the
+    kernel compiles on every device of a v5e:2x2."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    L, m = 16, 1
+    specs = (P(None, "data"), P(None, None, "data"), P())
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, spec))
+            for shape, dt, spec in zip(
+                [(L, ROWS), (m, L, ROWS), (m, J)],
+                [jnp.int32, jnp.float32, jnp.float32], specs)]
+    sharded = jax.shard_map(_gather, mesh=mesh, in_specs=specs,
+                            out_specs=P(None, "data"), check_vma=False)
+    compiled = jax.jit(sharded).lower(*args).compile()
+    text = compiled.as_text()
+    assert mesh.size == 4
+    assert "tpu_custom_call" in text and not re.findall(r" gather\(", text)
