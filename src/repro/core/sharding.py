@@ -54,7 +54,19 @@ __all__ = [
     "shard_instance",
     "DistributedMaximizer",
     "num_shards",
+    "scoped_psum",
 ]
+
+
+def scoped_psum(x, axes):
+    """`jax.lax.psum` under the `dist.psum` scope, which every collective of
+    the sharded solvers carries (docs/observability.md): a profiler trace
+    then tells the all-reduces, and any wait of a fast shard for a slow one,
+    from the oracle's work.  `x` is computed outside the scope, so it holds
+    the collective alone.  On a one-device mesh the TPU compiler drops the
+    collective and nothing carries the name."""
+    with jax.named_scope("dist.psum"):
+        return jax.lax.psum(x, axes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,9 +134,11 @@ def shard_instance(
 ) -> BucketedInstance:
     """Place instance arrays on the mesh with the column-shard layout.
 
-    Each host materialises only its local rows in a real multi-host deployment
-    (the paper's 'reads the shared instance directly from the network
-    filesystem'); here jax.device_put performs the equivalent placement.
+    One host process holds the whole instance in its memory and
+    `jax.device_put` sends each device its rows.  Placement in which each
+    host materialises only its own rows (the paper's shards read the shared
+    instance from a network filesystem) waits for a deployment larger than
+    one host (ROADMAP Queue 2 (b)).
     """
     specs = instance_pspecs(inst, dist.axes)
     return jax.tree.map(
@@ -160,12 +174,12 @@ def _make_calculate(local_obj: MatchingObjective, dist: DistConfig, rhs):
             # paper-faithful: reduce to rank 0, update there, broadcast back.
             # In SPMD both hops are all-reduces; the second one broadcasts the
             # rank-0 update by summing a one-hot-masked copy.
-            total = jax.lax.psum(contrib, axes)  # 'reduce' hop
+            total = scoped_psum(contrib, axes)  # 'reduce' hop
             rank = _linear_rank(axes)
             masked = jnp.where(rank == 0, total, jnp.zeros_like(total))
-            total = jax.lax.psum(masked, axes)  # 'broadcast' hop
+            total = scoped_psum(masked, axes)  # 'broadcast' hop
         else:
-            total = jax.lax.psum(contrib, axes)
+            total = scoped_psum(contrib, axes)
         total = total.astype(jnp.float32)
         ax, lin, ridge = total[:-2], total[-2], total[-1]
         grad = ax - rhs
@@ -177,6 +191,20 @@ def _make_calculate(local_obj: MatchingObjective, dist: DistConfig, rhs):
         )
 
     return calculate
+
+
+def _allreduce_bytes(dual_dim: int, dist: DistConfig, *, evaluations: int,
+                     power_steps: int, votes: int) -> int:
+    """Bytes one shard contributes to the solve's all-reduces: per oracle
+    evaluation (AGD iteration or final recovery) the m*J gradient and the
+    two packed scalars, at 2 B per element under bf16 compression and twice
+    under `comm_mode="rank0"`; per power step the m*J vector A u in fp32;
+    per stop vote one int32.  It depends on the dual dimension and the
+    schedule, never on sources or nonzeros (the paper's scaling property)."""
+    width = 2 if dist.compress in ("bf16", "bf16_ef") else 4
+    hops = 2 if dist.comm_mode == "rank0" else 1
+    return (evaluations * (dual_dim + 2) * width * hops
+            + power_steps * dual_dim * 4 + votes * 4)
 
 
 def _linear_rank(axes: tuple[str, ...]) -> jax.Array:
@@ -242,7 +270,7 @@ class DistributedMaximizer:
             trip count is identical on every shard by construction, which is
             what keeps the collectives inside the loop body from deadlocking.
             """
-            votes = jax.lax.psum(done.astype(jnp.int32), axes)
+            votes = scoped_psum(done.astype(jnp.int32), axes)
             return votes == n_shards
 
         @partial(
@@ -303,7 +331,7 @@ class DistributedMaximizer:
 
             def body(u, _):
                 atl = obj.apply_AT(u / jnp.linalg.norm(u))
-                au = jax.lax.psum(obj.apply_A(atl), axes)
+                au = scoped_psum(obj.apply_A(atl), axes)
                 return au, jnp.linalg.norm(au)
 
             with jax.named_scope("power_iteration"):
@@ -366,15 +394,23 @@ class DistributedMaximizer:
         )
         reg = telemetry.get_registry()
         reg.inc("dist_solves_total", 1, shards=shards)
+        iters, checks = len(cfg.gammas) * cfg.iters_per_stage, 0
         if iters_used is not None:
             # every shard votes once per check_every-chunk actually executed,
             # and budget minus iters_used is the work early stopping skipped
+            iters = sum(iters_used)
             checks = sum(-(-u // cfg.check_every) for u in iters_used)
             reg.inc("dist_early_stop_checks_total", checks * shards)
             reg.inc(
                 "dist_iters_saved_total",
                 sum(cfg.iters_per_stage - u for u in iters_used),
             )
+        reg.inc(
+            "dist_allreduce_bytes_total",
+            _allreduce_bytes(dual_dim, self.dist, evaluations=iters + 1,
+                             power_steps=cfg.power_iters, votes=checks),
+            shards=shards,
+        )
         return SolveResult(
             lam=lam, x_slabs=x_slabs, g=g, stats=tuple(stats),
             sigma_sq=sigma_sq, steps=tuple(steps),

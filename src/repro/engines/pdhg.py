@@ -593,15 +593,15 @@ def _sharded_fns(inst, mesh, cfg, dist, pcfg, projection):
     Shared by the run path (`solve_pdhg_sharded`) and the dry-run lowering
     path (`lower_pdhg_sharded`) so both compile the identical program.
     """
-    from repro.core.sharding import instance_pspecs, num_shards
+    from repro.core.sharding import instance_pspecs, num_shards, scoped_psum
     axes = dist.axes_tuple
     specs = instance_pspecs(inst, dist.axes)
     slab_specs = tuple(P(dist.axes, None) for _ in inst.buckets)
     n_shards = num_shards(mesh, dist)
-    psum = lambda v: jax.lax.psum(v, axes)  # noqa: E731
+    psum = lambda v: scoped_psum(v, axes)  # noqa: E731
 
     def psum_all_converged(done):
-        votes = jax.lax.psum(done.astype(jnp.int32), axes)
+        votes = psum(done.astype(jnp.int32))
         return votes == n_shards
 
     def local_objective(inst_local):
