@@ -147,10 +147,21 @@ def binned_segment_sum(idx: jax.Array, contrib: jax.Array, J: int) -> jax.Array:
 
 
 def _segment_sum_ax(bucket: Bucket, x: jax.Array, J: int) -> jax.Array:
-    """This bucket's contribution to A x: [m, J]."""
+    """This bucket's contribution to A x: [m, J], from the bucket as stored.
+
+    Scattered slot-major, through the transposed [L, n] slabs (see
+    `_gather_at_lam`).  On TPU the one-hot scatter kernel does the work
+    (`kernels.ops.dual_scatter`), reading narrow coefficients at their
+    storage width and dequantizing them in VMEM; else `binned_segment_sum`
+    of the fp32 contributions, as `dequantize_bucket` would give them.
+    """
+    from repro.kernels import ops as kops
+
     with oracle_scope("scatter", bucket.idx.shape[1]):
-        contrib = bucket.coeff * (x * bucket.mask)[None]  # [m, n, L]
-        return binned_segment_sum(bucket.idx, contrib, J)
+        return kops.dual_scatter(
+            bucket.idx.T, bucket.coeff.swapaxes(1, 2),
+            (_acc32(x) * bucket.mask).T, J, coeff_scale=bucket.coeff_scale,
+        )
 
 
 @dataclasses.dataclass
@@ -284,13 +295,17 @@ class MatchingObjective:
         return tuple(slabs)
 
     def apply_A(self, x_slabs: Sequence[jax.Array]) -> jax.Array:
-        """A x as a [m*J] vector (accumulated at >= fp32 for narrow slabs)."""
+        """A x as a [m*J] vector (accumulated at >= fp32 for narrow slabs).
+
+        Takes the stored buckets, not the fp32 views of `_buckets`: a
+        widening convert cannot fuse into the scatter kernel.
+        """
         inst = self.instance
         ax = jnp.zeros(
             (inst.num_families, inst.num_destinations),
             jnp.promote_types(x_slabs[0].dtype, jnp.float32),
         )
-        for b, x in zip(self._buckets, x_slabs):
+        for b, x in zip(inst.buckets, x_slabs):
             ax = ax + _segment_sum_ax(b, x, inst.num_destinations)
         return ax.reshape(-1)
 

@@ -39,7 +39,7 @@ against a 128-row one-hot) and about 5R + 260 elementwise vector
 operations; on a v5e at R = 80 the MXU bounds it (9.4 ms for 2.05·10^7
 slots, ~91 % of the bf16 peak), against ~7.5 ns per slot for XLA's
 per-element gather.  The work grows with R, the XLA gather's does not:
-`ops.gather_takes_kernel` takes the kernel up to R = 128 (J <= 16384 per
+`ops.onehot_takes_kernel` takes the kernel up to R = 128 (J <= 16384 per
 family), and while its VMEM, which grows with m·R, fits (`fits_vmem_budget`:
 m <= 4 at J = 16384).
 
@@ -92,7 +92,15 @@ def fits_vmem_budget(num_families: int, num_destinations: int) -> bool:
     double-buffered and its gathered duals in scratch, and per table row
     and family the bf16 tables (1 KiB, double-buffered) and the two fp32
     contraction results of a 512-source chunk (4 KiB).  At J = 16384 that
-    takes m <= 4; at J = 10^4, m <= 5."""
+    takes m <= 4; at J = 10^4, m <= 5.
+
+    The scatter kernel (kernels/scatter.py), which the same rule admits,
+    holds other buffers (per table row and family three fp32 piece
+    accumulators and the output block; per chunk the piece-scaled row
+    one-hot), but as the TPU compiler allocates them it fits this budget
+    at the rule's limits too: `test_scatter_compiles_at_its_vmem_limit`
+    (tests/test_tpu_compile.py) compiles it there for a v5e under a
+    scoped-VMEM limit of _VMEM_BUDGET."""
     R = table_rows(num_destinations)
     m = num_families
     need = 4 * _TILE_BYTES + 3 * m * _TILE_BYTES + 6 * 1024 * m * R

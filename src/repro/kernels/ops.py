@@ -9,10 +9,12 @@ Responsibilities (mirrors the paper's dispatch policy, §4.3):
     footprint: slot depth rounded up to the sublane tiling of the slab dtype
     (8 for fp32, 16 for bf16, 32 for int8), sources a multiple of 128 lanes;
     the last grid step may be partial and the kernels mask it;
-  * gather `A^T lam` ahead of the kernels (`dual_gather`): on TPU for
-    J <= 16384 per family, as many families as its VMEM holds, through the
-    one-hot MXU kernel (kernels/gather.py, `gather_takes_kernel`), else
-    through XLA's gather (`gather_atl`);
+  * gather `A^T lam` ahead of the kernels (`dual_gather`) and scatter-add
+    `A x` (`dual_scatter`): on TPU for J <= 16384 per family, as many
+    families as their VMEM holds, through the one-hot MXU kernels
+    (kernels/gather.py, kernels/scatter.py; `onehot_takes_kernel`), else
+    through XLA's gather (`gather_atl`) and segment sum
+    (`binned_segment_sum`);
   * fall back to the multi-op reference implementation for non-power-of-two
     slab widths and for widths beyond KERNEL_MAX_LENGTH = 512, where a
     single 128-source tile fills the VMEM budget (the paper's Triton kernel
@@ -32,7 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import gather_atl, oracle_scope
+from repro.core.objective import binned_segment_sum, gather_atl, oracle_scope
 from repro.kernels import ref as kref
 from repro.kernels.dual_oracle import (
     _padded_destinations,
@@ -45,17 +47,19 @@ from repro.kernels.gather import (
     make_gather_call,
     tile_rows,
 )
+from repro.kernels.scatter import make_scatter_call
 from repro.kernels.simplex_proj import MAX_FUSED_LENGTH, make_simplex_call
 
 __all__ = [
     "dual_gather",
+    "dual_scatter",
     "fused_project_simplex",
     "fused_dual_primal",
     "fused_dual_oracle",
     "fused_pdhg_step",
     "KERNEL_MAX_LENGTH",
-    "gather_takes_kernel",
     "onehot_gather",
+    "onehot_takes_kernel",
     "oracle_hist_partial_bytes",
     "oracle_takes_kernel",
     "oracle_slab_slot_bytes",
@@ -159,28 +163,29 @@ def _use_interpret(interpret) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def gather_takes_kernel(
+def onehot_takes_kernel(
     num_destinations: int, num_families: int, dtype, quantized: bool,
     interpret,
 ) -> bool:
-    """Whether `dual_gather` runs the one-hot kernel (kernels/gather.py) or
-    XLA's gather, for m families of J duals and coefficients stored as
-    `dtype`.
+    """Whether `dual_gather` and `dual_scatter` run their one-hot kernels
+    (kernels/gather.py, kernels/scatter.py) or XLA's gather and segment
+    sum, for m families of J duals and coefficients stored as `dtype`.
 
-    The kernel takes the TPU backend (`interpret=None`), or interpret mode
+    The kernels take the TPU backend (`interpret=None`), or interpret mode
     when asked (`interpret=True`), with a family's table at most
-    MAX_TABLE_ROWS = 128 rows of 128 duals (J <= 16384) and its VMEM, which
-    grows with m·R, within budget (`gather.fits_vmem_budget`: m <= 4 at
-    J = 16384, m <= 5 at J = 10^4).  Cost model, per
-    slot and family, R = ceil(J/128): the kernel's MXU work is 2·4·128·R
-    flops and its vector work about 5R + 260 operations; XLA's gather costs
-    ~7.5 ns per slot on a v5e whatever J is (1.3·10^8 slots/s at J = 10^4).
-    At R = 80 (J = 10^4) the kernel, MXU-bound, took 9.4 ms for 2.05·10^7
-    slots on a v5e against XLA's 157 ms: 0.46 ns per slot, growing in
-    proportion to R, so the two would cross near R = 1300 (J ~ 1.7·10^5).
-    The kernel stops at R = 128, where its tables and contraction results
-    still leave room in VMEM for several families; wider duals, or more
-    families than fit, take XLA.  Off-TPU without `interpret=True`, XLA.
+    MAX_TABLE_ROWS = 128 rows of 128 duals (J <= 16384) and their VMEM,
+    which grows with m·R, within budget (`gather.fits_vmem_budget`: m <= 4
+    at J = 16384, m <= 5 at J = 10^4).  Cost model, per slot and family,
+    R = ceil(J/128): the gather's MXU work is 2·4·128·R flops and its
+    vector work about 5R + 260 operations, the scatter's 2·3·128·R flops
+    and about 4R + 256 operations; XLA's gather costs ~7.5 ns per slot on a
+    v5e and its scatter-add ~7 ns, whatever J is.  At R = 80 (J = 10^4) the
+    gather kernel, MXU-bound, took 9.4 ms for 2.05·10^7 slots on a v5e
+    against XLA's 157 ms: 0.46 ns per slot, growing in proportion to R, so
+    the two would cross near R = 1300 (J ~ 1.7·10^5).  The kernels stop at
+    R = 128, where their tables still leave room in VMEM for several
+    families; wider duals, or more families than fit, take XLA.  Off-TPU
+    without `interpret=True`, XLA.
     """
     if interpret is None and jax.default_backend() != "tpu":
         return False
@@ -195,13 +200,13 @@ def dual_gather(idx, coeff, lam2, *, coeff_scale=None, interpret=None):
     `coeff_scale` ([m] values in any shape).
 
     idx [L, n] int32, coeff [m, L, n], lam2 [m, J].  Through the one-hot
-    kernel where `gather_takes_kernel` says so and the duals are fp32 (its
+    kernel where `onehot_takes_kernel` says so and the duals are fp32 (its
     result is `gather_atl`'s bit for bit at m = 1), under a nested
     `onehot` scope; else `gather_atl`.
     """
     m, J = lam2.shape
     quantized = coeff_scale is not None
-    if lam2.dtype == jnp.float32 and gather_takes_kernel(
+    if lam2.dtype == jnp.float32 and onehot_takes_kernel(
         J, m, coeff.dtype, quantized, interpret
     ):
         return onehot_gather(
@@ -231,6 +236,49 @@ def onehot_gather(idx, coeff, lam2, coeff_scale=None, *, interpret=None):
         operands.append(jnp.asarray(coeff_scale, jnp.float32).reshape(m, 1))
     with jax.named_scope("onehot"):
         return call(*operands)
+
+
+def dual_scatter(idx, coeff, v, num_destinations, *, coeff_scale=None,
+                 interpret=None):
+    """A x on one transposed [L, n] slab, [m, J] fp32: bin j of family k
+    sums coeff[k] · v over the slots whose idx is j, with int8
+    coefficients dequantized by `coeff_scale` ([m] values in any shape).
+
+    idx [L, n] int32, coeff [m, L, n] as stored (fp32, bf16, or int8 with
+    its scales: the kernel reads it at that width), v [L, n] (x · mask in
+    the oracle, A^T u · mask in a power step).  Through the one-hot kernel
+    where `onehot_takes_kernel` says so and v is fp32, under a nested
+    `onehot` scope; else `binned_segment_sum`.  The two sum in different
+    orders, so they agree to fp32 rounding, not bit for bit.  One more
+    difference: in the kernel a non-finite contribution (inf or NaN) at
+    index e makes NaN the whole row of 128 bins that holds e (bins
+    128·(e >> 7) to 128·(e >> 7) + 127), not only bin e.  A x, and so the
+    gradient, is non-finite either way, so a diverging solve still shows.
+    """
+    L, n = idx.shape
+    m = coeff.shape[0]
+    quantized = coeff_scale is not None
+    if v.dtype == jnp.float32 and onehot_takes_kernel(
+        num_destinations, m, coeff.dtype, quantized, interpret
+    ):
+        narrow = min(coeff.dtype, idx.dtype, key=lambda d: jnp.dtype(d).itemsize)
+        call = make_scatter_call(
+            n, L, m, num_destinations, pick_block_rows(n, tile_rows(L), narrow),
+            coeff.dtype, interpret=_use_interpret(interpret), quantized=quantized,
+        )
+        operands = [idx, coeff, v]
+        if quantized:
+            operands.append(jnp.asarray(coeff_scale, jnp.float32).reshape(m, 1))
+        with jax.named_scope("onehot"):
+            table = call(*operands)
+        return table.reshape(m, -1)[:, :num_destinations]
+    if quantized:
+        coeff = coeff.astype(jnp.float32) * jnp.asarray(
+            coeff_scale, jnp.float32
+        ).reshape((m,) + (1,) * (coeff.ndim - 1))
+    return binned_segment_sum(
+        idx.T, (coeff * v[None]).swapaxes(-1, -2), num_destinations
+    )
 
 
 def _gather_atl(idx, coeff, lam, num_destinations, coeff_scale=None,

@@ -6,7 +6,7 @@ flush to signed zeros in the product, as in XLA's), read through its
 coefficient multiply with one family's coefficients at 1; its A^T lam is
 `gather_atl`'s bit for bit at m = 1 and, with coefficients whose products
 with the duals are exact, at m = 3; and `dual_gather` takes it only where
-`gather_takes_kernel` says so.
+`onehot_takes_kernel` says so.
 """
 import textwrap
 
@@ -211,13 +211,13 @@ def _takes_kernel(J, m, interpret):
 ])
 def test_dispatch_rule(J, m, interpret, kernel):
     assert jax.default_backend() != "tpu"
-    assert kops.gather_takes_kernel(J, m, jnp.float32, False, interpret) == kernel
+    assert kops.onehot_takes_kernel(J, m, jnp.float32, False, interpret) == kernel
     assert _takes_kernel(J, m, interpret) == kernel
 
 
 def test_dispatch_dtypes():
     def takes(dtype, quantized):
-        return kops.gather_takes_kernel(10_000, 1, dtype, quantized, True)
+        return kops.onehot_takes_kernel(10_000, 1, dtype, quantized, True)
 
     assert takes(jnp.bfloat16, False)
     assert takes(jnp.int8, True)

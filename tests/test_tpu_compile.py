@@ -85,6 +85,26 @@ def _lower_gather(sharding, L, m, dtype, destinations=J):
     return jax.jit(_gather).lower(*args)
 
 
+def _scatter(idx, coeff, v, *scale, destinations=J):
+    return kops.dual_scatter(idx, coeff, v, destinations,
+                             coeff_scale=scale[0] if scale else None,
+                             interpret=False)
+
+
+def _lower_scatter(sharding, L, m, dtype, destinations=J):
+    """`ops.dual_scatter` on one transposed [L, n] slab into m families of
+    J bins, as the oracle calls it."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    args = [sds((L, ROWS), jnp.int32), sds((m, L, ROWS), dtype),
+            sds((L, ROWS), jnp.float32)]
+    if dtype == jnp.int8:
+        args.append(sds((m, 1, 1), jnp.float32))
+    return jax.jit(
+        lambda *a: _scatter(*a, destinations=destinations)).lower(*args)
+
+
 def _primal_step(fn):
     def call(idx, coeff, cost, mask, lam, gamma, *scales):
         return fn(
@@ -120,6 +140,11 @@ CASES = [
       for L in (1, 2, 4, 8, 16, 32)],
     *[("dual_gather", L, 3, dt) for dt in (jnp.float32, jnp.bfloat16, jnp.int8)
       for L in (1, 16)],
+    # the one-hot scatter-add of A x, likewise
+    *[("dual_scatter", L, 1, dt) for dt in (jnp.float32, jnp.bfloat16, jnp.int8)
+      for L in (1, 2, 4, 8, 16, 32)],
+    *[("dual_scatter", L, 3, dt) for dt in (jnp.float32, jnp.bfloat16, jnp.int8)
+      for L in (1, 16)],
 ]
 
 
@@ -136,6 +161,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, L, m, dtype):
         ).lower(v, v)
     elif kernel == "dual_gather":
         lowered = _lower_gather(one_chip, L, m, dtype)
+    elif kernel == "dual_scatter":
+        lowered = _lower_scatter(one_chip, L, m, dtype)
     else:
         fn = kops.fused_dual_primal if kernel == "dual_primal" else (
             kops.fused_dual_oracle
@@ -147,13 +174,16 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, L, m, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kernel", ["dual_oracle", "simplex_proj", "dual_gather"])
+@pytest.mark.parametrize(
+    "kernel", ["dual_oracle", "simplex_proj", "dual_gather", "dual_scatter"])
 def test_kernel_name_and_metadata_in_compiled_text(one_chip, kernel):
     """The kernels carry their names and metadata into the compiled op's
     HLO text, which is the op's name in a profiler trace, and the dual
     oracle's ops sit under the program's `oracle.*` scopes
     (docs/observability.md): on the chip the oracle's gather is the
-    `dual_gather` kernel, under `oracle.gather/L<w>/onehot`."""
+    `dual_gather` kernel, under `oracle.gather/L<w>/onehot`, and its
+    scatter-add the `dual_scatter` kernel, under `oracle.scatter/L<w>/onehot`
+    (test_unfused_oracle_scatters_through_the_kernel)."""
     L = 16
     if kernel == "simplex_proj":
         v = jax.ShapeDtypeStruct((ROWS, L), jnp.float32, sharding=one_chip)
@@ -162,6 +192,8 @@ def test_kernel_name_and_metadata_in_compiled_text(one_chip, kernel):
         ).lower(v, v)
     elif kernel == "dual_gather":
         lowered = _lower_gather(one_chip, L, 1, jnp.float32)
+    elif kernel == "dual_scatter":
+        lowered = _lower_scatter(one_chip, L, 1, jnp.float32)
     else:
         lowered = jax.jit(_primal_step(kops.fused_dual_oracle)).lower(
             *_slab_args(one_chip, L, 1, jnp.float32)
@@ -185,6 +217,9 @@ def test_kernel_name_and_metadata_in_compiled_text(one_chip, kernel):
     if kernel == "dual_gather":
         assert "onehot/dual_gather" in text
         assert not re.findall(r" gather\(", text)
+    if kernel == "dual_scatter":
+        assert "onehot/dual_scatter" in text
+        assert not re.findall(r" scatter\(", text)
 
 
 @pytest.mark.parametrize("destinations,m,L", [
@@ -199,6 +234,79 @@ def test_gather_compiles_at_its_vmem_limit(one_chip, destinations, m, L):
     text = _lower_gather(one_chip, L, m, jnp.float32, destinations).compile(
     ).as_text()
     assert "tpu_custom_call" in text and not re.findall(r" gather\(", text)
+
+
+@pytest.mark.parametrize("destinations,m,L", [
+    (16_384, 3, 16), (16_384, 4, 1), (16_384, 4, 16), (10_000, 5, 16),
+    (1000, 8, 16)])
+def test_scatter_compiles_at_its_vmem_limit(one_chip, monkeypatch,
+                                           destinations, m, L):
+    """The most families `gather.fits_vmem_budget` lets the one-hot kernels
+    take, at the widest fp32 tiles: the scatter kernel compiles too, within
+    the budget that rule plans for (its scoped VMEM held to _VMEM_BUDGET)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.gather import _VMEM_BUDGET, fits_vmem_budget
+
+    assert fits_vmem_budget(m, destinations)
+    pallas_call = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call", lambda *a, **k: pallas_call(
+        *a, **k, compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_BUDGET)))
+    text = _lower_scatter(one_chip, L, m, jnp.float32, destinations).compile(
+    ).as_text()
+    assert "tpu_custom_call" in text and not re.findall(r" scatter\(", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_unfused_oracle_scatters_through_the_kernel(one_chip, monkeypatch,
+                                                    dtype):
+    """The unfused oracle's `calculate` compiled for the chip: A x is the
+    `dual_scatter` kernel in every bucket, under `oracle.scatter/L<w>/
+    onehot`, and no XLA scatter is left under `oracle.scatter`; narrow
+    slabs reach the kernel at their storage width.  The dispatch asks
+    `jax.default_backend()`, which here is the CPU: the test answers for
+    the chip it compiles for."""
+    from repro.core import MatchingObjective
+    from repro.instances.buckets import Bucket, BucketedInstance
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    widths = (1, 8, 16)
+    scales = {}
+    if dtype == jnp.int8:
+        scales = dict(coeff_scale=sds((1, 1, 1), jnp.float32),
+                      cost_scale=sds((1, 1), jnp.float32))
+    inst = BucketedInstance(
+        buckets=tuple(
+            Bucket(idx=sds((ROWS, L), jnp.int32),
+                   coeff=sds((1, ROWS, L), dtype),
+                   cost=sds((ROWS, L), dtype),
+                   mask=sds((ROWS, L), dtype), length=L, **scales)
+            for L in widths),
+        rhs=sds((J,), jnp.float32), num_sources=len(widths) * ROWS,
+        num_destinations=J, num_families=1)
+
+    def grad(inst, lam):
+        return MatchingObjective(inst).calculate(lam, 0.1).grad
+
+    text = jax.jit(grad).lower(inst, sds((J,), jnp.float32)).compile().as_text()
+    for L in widths:
+        assert f"oracle.scatter/L{L}/onehot/dual_scatter" in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.match(r"\s*(ROOT )?%dual_scatter\b", line)]
+    assert len(calls) == len(widths)
+    for call in calls:
+        meta = re.search(r"kernel_metadata=\{(.*?)\}",
+                         text[text.index(call):], re.S)
+        assert f'"dtype":"{jnp.dtype(dtype).name}"' in meta.group(1)
+    scatters = re.findall(r" scatter\(.*?op_name=\"([^\"]*)\"", text)
+    assert not [n for n in scatters if "oracle.scatter" in n]
 
 
 @pytest.mark.parametrize("shared", [False, True], ids=["batched", "shared"])
@@ -240,3 +348,46 @@ def test_gather_compiles_inside_shard_map(one_chip, topo):
     text = compiled.as_text()
     assert mesh.size == 4
     assert "tpu_custom_call" in text and not re.findall(r" gather\(", text)
+
+
+@pytest.mark.parametrize("shared", [False, True], ids=["batched", "shared"])
+def test_scatter_compiles_under_vmap(one_chip, shared):
+    """The service's batched pool vmaps the solve over tenants (J = 10^3):
+    the scatter's pallas_call under jax.vmap, with each tenant's slabs or
+    one slab shared by the tenants, and each tenant's values."""
+    T, L, m, n, destinations = 4, 8, 1, ROWS // 4, 1000
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lead = () if shared else (T,)
+    args = [sds(lead + (L, n), jnp.int32), sds(lead + (m, L, n), jnp.float32),
+            sds((T, L, n), jnp.float32)]
+    batched = jax.vmap(
+        lambda i, c, v: _scatter(i, c, v, destinations=destinations),
+        in_axes=(None if shared else 0,) * 2 + (0,))
+    text = jax.jit(batched).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and not re.findall(r" scatter\(", text)
+
+
+def test_scatter_compiles_inside_shard_map(topo):
+    """The sharded solve scatter-adds inside shard_map (sources split over
+    the mesh, each chip's bins all-reduced): the kernel compiles on every
+    device of a v5e:2x2."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    L, m = 16, 1
+    specs = (P(None, "data"), P(None, None, "data"), P(None, "data"))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, spec))
+            for shape, dt, spec in zip(
+                [(L, ROWS), (m, L, ROWS), (L, ROWS)],
+                [jnp.int32, jnp.float32, jnp.float32], specs)]
+    sharded = jax.shard_map(
+        lambda i, c, v: jax.lax.psum(_scatter(i, c, v), "data"), mesh=mesh,
+        in_specs=specs, out_specs=P(), check_vma=False)
+    text = jax.jit(sharded).lower(*args).compile().as_text()
+    assert mesh.size == 4
+    assert "tpu_custom_call" in text and not re.findall(r" scatter\(", text)
+    assert "all-reduce" in text
